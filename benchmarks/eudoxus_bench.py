@@ -1264,7 +1264,8 @@ def serving_suite(n_frames: int = 8, chunk: int = 2, capacity: int = 3,
         traces_after_compile = pool.chunk_trace_count()
         # steady-state wall times only: drop the compile chunks
         engine.tracker = StepTimeTracker()
-        engine.decomp = {k: StepTimeTracker() for k in engine.decomp}
+        for tr in engine.decomp.values():
+            tr.samples.clear()
 
         rng = np.random.RandomState(seed)
         # arrival times in units of chunk boundaries, mean one robot

@@ -36,7 +36,8 @@ feedback); SLAM robots' append-only replay always defers one chunk.
 """
 from __future__ import annotations
 
-from typing import NamedTuple, Optional, Tuple
+import contextlib
+from typing import Callable, ContextManager, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +60,12 @@ from repro.distributed.fleet_mesh import (chunk_sharding, fleet_mesh,
                                           mesh_shards, padded_batch,
                                           robot_sharding, shard_fleet_chunk,
                                           shard_fleet_step, shard_states)
+
+
+def no_span(name: str) -> ContextManager:
+    """The default ``span`` of a dispatch: names and times nothing
+    (the serving engine passes its own, see ``repro.serve.spans``)."""
+    return contextlib.nullcontext()
 
 
 class ChunkHostWork(NamedTuple):
@@ -351,7 +358,8 @@ class FleetLocalizer:
     def dispatch_chunk(self, states: LocalizerState, imgs_l, imgs_r,
                        imu_accel, imu_gyro, gps, mode_ids, dt_imu: float,
                        active=None, stager: Optional[_ChunkStager] = None,
-                       base_idx: Optional[np.ndarray] = None
+                       base_idx: Optional[np.ndarray] = None,
+                       span: Callable[[str], ContextManager] = no_span
                        ) -> Tuple[LocalizerState, FrameOutputs,
                                   ChunkHostWork]:
         """The dispatch FRONT of ``step_chunk``: stage + dispatch one
@@ -360,29 +368,37 @@ class FleetLocalizer:
         dispatched chunk, with one caveat: ``base_idx=None`` reads
         ``states.frame_idx`` to the host, which waits for the PREVIOUS
         chunk. Pipelined callers (the serving pool) pass their own
-        host-tracked frame bases so the dispatch front never syncs."""
+        host-tracked frame bases so the dispatch front never syncs.
+
+        ``span(name)`` wraps the host's three steps: ``fleet.build``
+        (the chunk's host arrays and plan), ``fleet.h2d`` (their
+        placement on the device) and ``fleet.enqueue`` (the jitted
+        call)."""
         K = np.asarray(imgs_l).shape[0]
         mode_np = self.scenarios.validate_ids(mode_ids)
         act, n_real = self._active_mask(K, active)
         if base_idx is None:
             base_idx = np.asarray(states.frame_idx)  # pre-chunk, per robot
 
-        inputs_np = self._build_chunk(imgs_l, imgs_r, imu_accel, imu_gyro,
-                                      gps, mode_np, act)
+        with span("fleet.build"):
+            inputs_np = self._build_chunk(imgs_l, imgs_r, imu_accel,
+                                          imu_gyro, gps, mode_np, act)
+            plan = self._chunk_plan(n_real)
+            flags = self._fleet_flags(plan, mode_np)
         # external callers (the serving pool) may own a persistent
         # _ChunkStager: staging then rides the input ring (pre-sharded
         # device_put, committed async H2D on accelerators) instead of
         # the default one-shot placement
-        if stager is None:
-            inputs = self._put(inputs_np, self._chunk_in_sharding)
-            staged = None
-        else:
-            staged = stager.stage(inputs_np, self._chunk_in_sharding)
-            inputs = staged.inputs
-        plan = self._chunk_plan(n_real)
-        states, outs = self._fused_fleet_chunk(
-            states, inputs, self._fleet_flags(plan, mode_np),
-            jnp.float32(dt_imu))
+        with span("fleet.h2d"):
+            if stager is None:
+                inputs = self._put(inputs_np, self._chunk_in_sharding)
+                staged = None
+            else:
+                staged = stager.stage(inputs_np, self._chunk_in_sharding)
+                inputs = staged.inputs
+        with span("fleet.enqueue"):
+            states, outs = self._fused_fleet_chunk(
+                states, inputs, flags, jnp.float32(dt_imu))
         if staged is not None:
             staged.consumed = True       # buffers donated to the dispatch
         self.dispatch_count += 1

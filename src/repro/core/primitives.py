@@ -130,6 +130,8 @@ class FrameCarry:
     upd_uv: Any = None
     upd_valid: Any = None
     upd_skipped: Any = None
+    upd_count: Any = None      # tracks consumed this frame
+    upd_applied: Any = None    # the in-program MSCKF update ran
 
 
 def _replace(c: FrameCarry, **kw) -> FrameCarry:
@@ -272,7 +274,9 @@ def _msckf_update(ctx: FrameCtx, c: FrameCarry,
     return _replace(c, filt=filt, tracks_valid=tracks_valid,
                     upd_uv=jnp.where(upd_skipped, uv, 0.0),
                     upd_valid=jnp.where(upd_skipped, vd, False),
-                    upd_skipped=upd_skipped)
+                    upd_skipped=upd_skipped,
+                    upd_count=jnp.where(do_consume, count, 0),
+                    upd_applied=do_consume & gate)
 
 
 # --------------------------------------------------------------------------

@@ -323,6 +323,20 @@ class FrameOutputs(NamedTuple):
     upd_valid: jax.Array   # (max_updates, W) bool
     upd_skipped: jax.Array  # () bool — tracks were consumed but the
     #                         in-scan update was gated off this frame
+    counts: jax.Array   # (4,) int32 work done by the frame (``COUNTS``)
+
+
+# what ``FrameOutputs.counts`` counts, in order: live track slots after
+# the frame, tracks consumed, the in-program MSCKF update applied (0/1),
+# the update shipped to the host instead (0/1, ``upd_skipped``)
+COUNTS = ("tracks", "consumed", "updates_applied", "updates_shipped")
+
+
+def _frame_counts(c: prim.FrameCarry) -> jax.Array:
+    return jnp.stack([jnp.sum(c.tracks_valid[:, -1], dtype=jnp.int32),
+                      c.upd_count.astype(jnp.int32),
+                      c.upd_applied.astype(jnp.int32),
+                      c.upd_skipped.astype(jnp.int32)])
 
 
 # --------------------------------------------------------------------------
@@ -449,12 +463,17 @@ def localize_step(state: LocalizerState, img_l: jax.Array, img_r: jax.Array,
         ba_ran=jnp.bool_(False),
         upd_uv=jnp.zeros((tracks.MAX_UPDATES, w, 2), jnp.float32),
         upd_valid=jnp.zeros((tracks.MAX_UPDATES, w), bool),
-        upd_skipped=jnp.bool_(False))
+        upd_skipped=jnp.bool_(False), upd_count=jnp.int32(0),
+        upd_applied=jnp.bool_(False))
 
-    # --- shared spine: mode-independent, unconditional, declared order
+    # --- shared spine: mode-independent, unconditional, declared order.
+    # Every primitive's stage, here and below, runs under a named scope
+    # of its registry name: the compiled program's op metadata, and so a
+    # device trace, tells the primitives' ops apart
     for use_ in table.spine:
         p = prim.get_primitive(use_.name)
-        c = p.stage(ctx, c, use_.param_dict())
+        with jax.named_scope(p.name):
+            c = p.stage(ctx, c, use_.param_dict())
 
     # --- per-scenario switch: each scenario's light filter work becomes
     # one branch (params baked per branch); branch n_scen = pass-through
@@ -462,8 +481,9 @@ def localize_step(state: LocalizerState, img_l: jax.Array, img_r: jax.Array,
         def br(filt):
             c2 = dataclasses.replace(c, filt=filt)
             for u in uses:
-                f_new = prim.get_primitive(u.name).stage(
-                    ctx, c2, u.param_dict())
+                p = prim.get_primitive(u.name)
+                with jax.named_scope(p.name):
+                    f_new = p.stage(ctx, c2, u.param_dict())
                 c2 = dataclasses.replace(c2, filt=f_new)
             return c2.filt
         return br
@@ -493,7 +513,8 @@ def localize_step(state: LocalizerState, img_l: jax.Array, img_r: jax.Array,
                   _uses=uses_arr):
             def run(op2):
                 c2 = dataclasses.replace(_c, **dict(zip(_g.writes, op2)))
-                return _p.stage(ctx, c2, _params)
+                with jax.named_scope(_p.name):
+                    return _p.stage(ctx, c2, _params)
             return jax.lax.cond(_uses[safe_mode], run, lambda op2: op2, op)
 
         vals = jax.lax.cond(active_any, _live, lambda op: op, operand)
@@ -507,7 +528,7 @@ def localize_step(state: LocalizerState, img_l: jax.Array, img_r: jax.Array,
     outs = FrameOutputs(fr=c.fr, p=c.filt.p, q=c.filt.q, hist=c.hist,
                         ba_cost=c.ba.last_cost, ba_ran=c.ba_ran,
                         upd_uv=c.upd_uv, upd_valid=c.upd_valid,
-                        upd_skipped=c.upd_skipped)
+                        upd_skipped=c.upd_skipped, counts=_frame_counts(c))
     return new_state, outs
 
 
@@ -537,7 +558,8 @@ def _zero_outputs(state: LocalizerState, vocab: jax.Array,
                         upd_uv=jnp.zeros((tracks.MAX_UPDATES, w, 2),
                                          jnp.float32),
                         upd_valid=jnp.zeros((tracks.MAX_UPDATES, w), bool),
-                        upd_skipped=jnp.bool_(False))
+                        upd_skipped=jnp.bool_(False),
+                        counts=jnp.zeros((len(COUNTS),), jnp.int32))
 
 
 def frame_transition(state: LocalizerState, inp: FrameInputs,

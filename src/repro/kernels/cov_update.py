@@ -107,6 +107,7 @@ def fused_update(P: jax.Array, F_seq: jax.Array, Q: jax.Array,
         out_specs=pl.BlockSpec((d, d), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((d, d), P.dtype),
         interpret=interpret,
+        name="cov_update",
     )(F_seq, Q, gate, P)
 
 

@@ -245,6 +245,7 @@ def fe_cells(img: jax.Array, cfg, *, block_cells: int = 8,
                                        jnp.float32),
                             pltpu.SemaphoreType.DMA((2,))],
             interpret=interpret,
+            name="frontend_fused_cells",
         )(P)
     else:
         # overlapping slabs: element-offset row blocks
@@ -256,6 +257,7 @@ def fe_cells(img: jax.Array, cfg, *, block_cells: int = 8,
             out_specs=out_specs,
             out_shape=out_shape,
             interpret=interpret,
+            name="frontend_fused_cells",
         )(P)
     return (smooth, best.reshape(nt * bc, wc), idx.reshape(nt * bc, wc))
 
@@ -310,6 +312,7 @@ def _describe(smooth: jax.Array, yx: jax.Array, interpret: bool,
         out_shape=[jax.ShapeDtypeStruct((n, orb.N_BITS), jnp.uint8),
                    jax.ShapeDtypeStruct((n, 8), jnp.uint32)],
         interpret=interpret,
+        name="frontend_fused_describe",
     )(smooth, yx, jnp.asarray(cdy), jnp.asarray(cdx),
       jnp.asarray(orb.PAIRS))
 
@@ -390,6 +393,7 @@ def match_packed(pk_l, yxl, vl, pk_r, yxr, vr, *, max_disparity: int,
                    jax.ShapeDtypeStruct((NL, 1), jnp.int32),
                    jax.ShapeDtypeStruct((NL, 1), jnp.float32)],
         interpret=interpret,
+        name="frontend_fused_match",
     )(pk_l, yxl, vl.astype(jnp.int32)[:, None],
       pk_r, yxr.T, vr.astype(jnp.int32)[None, :])
     return stereo.StereoMatches(

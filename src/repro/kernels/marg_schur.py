@@ -164,6 +164,7 @@ def accumulate(g: jax.Array, a: jax.Array, b: jax.Array, *,
         out_shape=[jax.ShapeDtypeStruct((dp, dp), g.dtype),
                    jax.ShapeDtypeStruct((dp, 1), g.dtype)],
         interpret=interpret,
+        name="marg_schur_accumulate",
     )(gt.reshape(3 * dp, m), a.reshape(m, 9).T, b.T)
     return yy[:d, :d], yv[:d, 0]
 
@@ -315,6 +316,7 @@ def accumulate_normal(r: jax.Array, jx: jax.Array, jl: jax.Array, *,
                             for o in ops]
             + [pltpu.SemaphoreType.DMA((2,))] * 3,
             interpret=interpret,
+            name="marg_schur_normal",
         )(*ops)
     else:
         yy, yv = pl.pallas_call(
@@ -326,6 +328,7 @@ def accumulate_normal(r: jax.Array, jx: jax.Array, jl: jax.Array, *,
                        pl.BlockSpec((dp, 1), lambda i: (0, 0))],
             out_shape=out_shape,
             interpret=interpret,
+            name="marg_schur_normal",
         )(*ops)
     # kernel rows are (i, k); the reference layout is d = 6k + i
     yy = yy.reshape(6, kp, 6, kp)[:, :k, :, :k].transpose(1, 0, 3, 2)

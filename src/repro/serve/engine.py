@@ -26,12 +26,17 @@ an immediate drain, mirroring ``FleetLocalizer.run``'s per-robot
 flush policy; ``flush()`` drains the tail.
 
 Per-chunk wall time rides ``launch.watchdog.StepTimeTracker``
-(``snapshot()`` reports without resetting), decomposed into
-stage/dispatch/sync/host-stage trackers so ``latency_report`` says
-where the time lives. Per-pose latency is submit-to-drain — stamped
-when the pose is actually synced, NOT when its chunk was dispatched —
-with the queue wait (submit-to-dispatch) reported separately from the
-in-flight remainder.
+(``snapshot()`` reports without resetting), decomposed by the host
+spans of ``serve.spans`` (stage, dispatch and its build/h2d/enqueue/
+feedback parts, sync, host stage) so ``latency_report`` says where the
+time lives; each span is also marked in a profiler trace. Per-pose
+latency runs from the frame's arrival (``submit_frame``'s ``t``, by
+default the submit) to the drain — stamped when the pose is actually
+synced, NOT when its chunk was dispatched — and splits into the queue
+wait (arrival-to-dispatch) and the in-flight remainder (dispatch-to-
+sync). The chunk program's per-frame work counters (tracks, consumed
+tracks, MSCKF updates applied in the program or shipped to the host)
+are summed per robot and reported with the flush count.
 
 Overflow policy is explicit: ``overflow="resize"`` grows the pool
 (the slow, retrace-counting path — the pipeline is flushed first),
@@ -39,6 +44,7 @@ Overflow policy is explicit: ``overflow="resize"`` grows the pool
 """
 from __future__ import annotations
 
+import functools
 import time
 from collections import deque
 from typing import Any, Deque, Dict, List, Optional, Tuple
@@ -46,9 +52,11 @@ from typing import Any, Deque, Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.core.environment import MODE_VIO
+from repro.core.step import COUNTS
 from repro.launch.watchdog import StepTimeTracker
 from repro.serve.pool import (InFlightChunk, PoolFull, RobotStatePool,
                               SlotTicket, UnknownRobot)
+from repro.serve.spans import Spans
 
 
 class ServingEngine:
@@ -64,6 +72,8 @@ class ServingEngine:
         or ``"reject"`` (count and drop the join).
     tracker: optional ``StepTimeTracker`` for per-chunk drain wall
         time (a fresh one is created by default).
+    clock: the engine's clock (seconds): frame arrivals, latencies
+        and the host spans are read on it.
     inflight: max chunks dispatched but not yet drained (the pipeline
         depth). 2 (default) gathers/stages/dispatches chunk N+1 while
         chunk N executes and syncs poses one chunk behind; 1 is the
@@ -119,23 +129,33 @@ class ServingEngine:
         self._clock = clock
         # FIFO control queue: ("join"|"leave"|"assign", robot_id, arg)
         self._requests: Deque[Tuple[str, Any, Any]] = deque()
-        # robot id -> deque of (submit_time, frame tuple) single frames
+        # robot id -> deque of (arrival time, frame tuple) single frames
         self._streams: Dict[Any, Deque[Tuple[float, Tuple]]] = {}
         self._priority: Dict[Any, int] = {}
         # dispatched-but-undrained chunks, oldest first (drain is FIFO)
         self._inflight: Deque[InFlightChunk] = deque()
         self.peak_inflight = 0
         self.tickets: Dict[Any, SlotTicket] = {}
-        # submit-to-pose latency (stamped at the DRAIN point) and its
-        # queue-wait component (submit-to-dispatch), per robot
+        # arrival-to-pose latency (stamped at the DRAIN point) and its
+        # queue-wait component (arrival-to-dispatch), per robot
         self.latencies: Dict[Any, List[float]] = {}
         self.queue_waits: Dict[Any, List[float]] = {}
-        # where one chunk boundary's wall time lives: host gather into
-        # the staging buffers / dispatch enqueue / blocking pose sync /
-        # deferred host map stage
-        self.decomp = {name: StepTimeTracker()
-                       for name in ("stage", "dispatch", "sync",
-                                    "host_stage")}
+        # where one chunk boundary's wall time lives: the host spans
+        # (``serve.spans``: stage, dispatch = build + h2d + enqueue +
+        # feedback, sync, host_stage), one sample per chunk each; and,
+        # one sample per frame, its queue wait and its in-flight time
+        # (dispatch-to-sync)
+        self._spans = Spans(clock)
+        self.decomp = dict(self._spans.trackers,
+                           queue_wait=StepTimeTracker(),
+                           in_flight=StepTimeTracker())
+        self.dispatches = 0          # chunk sequence numbers handed out
+        # the chunk program's work counters (``step.COUNTS``) summed over
+        # every real frame drained, per robot and in total, and the
+        # chunks that forced a feedback flush
+        self.counts = np.zeros(len(COUNTS), np.int64)
+        self.robot_counts: Dict[Any, np.ndarray] = {}
+        self.flushes = 0
         self.chunks = 0
         self.frames_served = 0
         self.rejected = 0
@@ -162,12 +182,15 @@ class ServingEngine:
         self._requests.append(("assign", robot_id, scenario))
 
     def submit_frame(self, robot_id, img_l, img_r, imu_accel, imu_gyro,
-                     gps=None) -> None:
+                     gps=None, t: Optional[float] = None) -> None:
         """Queue one frame for ``robot_id`` (joined, or join queued).
         Frames submitted before the join drains are held and served in
-        the robot's first chunk after admission."""
+        the robot's first chunk after admission. ``t`` is the frame's
+        arrival on the engine's clock (a sensor's capture time, say;
+        default now): its queue wait and latency count from there."""
         self._streams.setdefault(robot_id, deque()).append(
-            (self._clock(), (img_l, img_r, imu_accel, imu_gyro, gps)))
+            (self._clock() if t is None else float(t),
+             (img_l, img_r, imu_accel, imu_gyro, gps)))
 
     def pending_requests(self) -> int:
         return len(self._requests)
@@ -252,7 +275,29 @@ class ServingEngine:
         order = self._gather_order()
         if not order:
             return None
-        t0 = time.perf_counter()
+        seq = self.dispatches
+        self.dispatches += 1
+        span = functools.partial(self._spans, chunk=seq)
+        with span("engine.stage"):
+            staging, counts, manifest, stamps = self._gather(order)
+        with span("engine.dispatch"):
+            fl = self.pool.dispatch_staged(staging, counts, manifest,
+                                           self.dt_imu, span=span)
+        now = self._clock()
+        fl.meta.update(seq=seq, stamps=stamps, dispatched=now)
+        for rid, ts in stamps.items():
+            waits = [now - t for t in ts]
+            self.queue_waits.setdefault(rid, []).extend(waits)
+            for w in waits:
+                self.decomp["queue_wait"].add(w)
+        if fl.needs_flush:
+            self.flushes += 1
+        return fl
+
+    def _gather(self, order: List[Any]):
+        """Write the queued frames of ``order``'s robots into the next
+        staging set: (staging set, per-slot frame counts, manifest,
+        robot id -> arrival times of its gathered frames)."""
         ipf = int(np.asarray(
             self._streams[order[0]][0][1][2]).shape[0])
         staging = self.pool.acquire_staging(self.chunk, ipf)
@@ -282,34 +327,29 @@ class ServingEngine:
             stamps[rid] = ts
             if remaining is not None:
                 remaining -= take
-        t1 = time.perf_counter()
-        self.decomp["stage"].add(t1 - t0)
-        fl = self.pool.dispatch_staged(staging, counts, manifest,
-                                       self.dt_imu)
-        self.decomp["dispatch"].add(time.perf_counter() - t1)
-        now = self._clock()
-        fl.meta["stamps"] = stamps
-        for rid, ts in stamps.items():
-            self.queue_waits.setdefault(rid, []).extend(
-                now - t for t in ts)
-        return fl
+        return staging, counts, manifest, stamps
 
     def _drain_oldest(self, poses: Dict[Any, List[np.ndarray]]) -> None:
         """Drain the oldest in-flight chunk: the one blocking pose sync
         (plus its deferred host stage), latency stamped at THIS point —
         the time the pose actually became available to the caller."""
         fl = self._inflight.popleft()
-        out = self.pool.drain_chunk(fl)
-        self.decomp["sync"].add(fl.meta.get("sync_s", 0.0))
-        self.decomp["host_stage"].add(fl.meta.get("host_s", 0.0))
+        out = self.pool.drain_chunk(
+            fl, span=functools.partial(self._spans, chunk=fl.meta["seq"]))
         now = self._clock()
-        stamps = fl.meta.get("stamps", {})
+        stamps = fl.meta["stamps"]
+        in_flight = now - fl.meta["dispatched"]
         for rid, p in out.items():
             poses.setdefault(rid, []).append(p)
             ts = stamps.get(rid, ())
             self.latencies.setdefault(rid, []).extend(
                 now - t for t in ts)
+            for _ in ts:
+                self.decomp["in_flight"].add(in_flight)
             self.frames_served += len(ts)
+        for rid, c in fl.counts.items():
+            self.counts += c
+            self.robot_counts[rid] = self.robot_counts.get(rid, 0) + c
 
     def _maybe_shrink(self, poses: Dict[Any, List[np.ndarray]]) -> None:
         """Shrink-on-idle: after ``shrink_after`` consecutive boundaries
@@ -439,13 +479,27 @@ class ServingEngine:
         return {"p50_s": float(np.percentile(a, 50)) if a.size else 0.0,
                 "p99_s": float(np.percentile(a, 99)) if a.size else 0.0}
 
+    def counters(self) -> Dict[str, Any]:
+        """The chunk program's work counters over every real frame
+        drained (``step.COUNTS``: live track slots summed over frames,
+        tracks consumed, MSCKF updates applied in the program, updates
+        shipped to the host), in total and per robot, with the frames
+        counted and the chunks that forced a feedback flush."""
+        def named(c, frames):
+            return {"frames": int(frames),
+                    **{k: int(v) for k, v in zip(COUNTS, c)}}
+        per_robot = {str(rid): named(c, len(self.latencies.get(rid, ())))
+                     for rid, c in self.robot_counts.items()}
+        return {**named(self.counts, self.frames_served),
+                "flushes": self.flushes, "per_robot": per_robot}
+
     def latency_report(self) -> Dict[str, Any]:
         """Gateway-facing summary: per-chunk drain stats (via the
         tracker's non-resetting ``snapshot``), the chunk-boundary
-        stage/dispatch/sync/host-stage decomposition, per-robot p50/p99
-        submit-to-pose latency split into queue wait (submit-to-
-        dispatch) and pipeline residence (dispatch-to-drain), and the
-        churn/retrace counters."""
+        decomposition by host span and per-frame wait, per-robot p50/p99
+        arrival-to-pose latency split into queue wait (arrival-to-
+        dispatch) and pipeline residence (dispatch-to-sync), the work
+        counters, and the churn/retrace counters."""
         per_robot = {}
         for rid, lat in self.latencies.items():
             st = {"frames": int(len(lat)), **self._pcts(lat)}
@@ -467,6 +521,7 @@ class ServingEngine:
             "decomposition": {name: tr.snapshot()
                               for name, tr in self.decomp.items()},
             "per_robot": per_robot,
+            "counters": self.counters(),
             "pool": {
                 "capacity": self.pool.capacity,
                 "occupancy": self.pool.occupancy,

@@ -34,16 +34,15 @@ program, counted separately (``retired_chunk_traces``).
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, ContextManager, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.environment import MODE_VIO
-from repro.core.fleet import FleetLocalizer
+from repro.core.fleet import FleetLocalizer, no_span
 from repro.core.localizer import _ChunkStager, init_localizer_state
 from repro.distributed.fleet_mesh import shard_states
 
@@ -120,10 +119,13 @@ class InFlightChunk:
     caller in the buffer destructor (~the chunk's full device time on
     the CPU runtime) — the one hidden sync that would serialize the
     whole pipeline. Held here, the destructor runs at drain time, when
-    the execution has provably completed and deletion is free."""
+    the execution has provably completed and deletion is free.
+
+    ``counts`` is filled by the drain: robot id -> its frames' summed
+    ``FrameOutputs.counts`` (real frames of its manifest slot only)."""
 
     __slots__ = ("outs", "manifest", "staging", "pending_slam",
-                 "needs_flush", "retired", "meta")
+                 "needs_flush", "retired", "meta", "counts")
 
     def __init__(self, outs, manifest, staging, pending_slam,
                  needs_flush, retired=None):
@@ -134,6 +136,7 @@ class InFlightChunk:
         self.needs_flush = needs_flush
         self.retired = retired
         self.meta = {}               # caller scratch (engine timestamps)
+        self.counts: Dict[Any, np.ndarray] = {}
 
 
 class StaleGeneration(RuntimeError):
@@ -412,7 +415,9 @@ class RobotStatePool:
 
     def dispatch_staged(self, staging: _StagingSet, counts: np.ndarray,
                         manifest: Tuple[Tuple[Any, int, int], ...],
-                        dt_imu: float) -> InFlightChunk:
+                        dt_imu: float,
+                        span: Callable[[str], ContextManager] = no_span
+                        ) -> InFlightChunk:
         """Dispatch one gathered chunk WITHOUT syncing its outputs.
 
         ``staging`` holds the written frames, ``counts`` the per-slot
@@ -423,7 +428,9 @@ class RobotStatePool:
         the host-Kalman fallback sync only the slices they need and
         mark the chunk ``needs_flush``; SLAM replay — append-only
         bookkeeping — is deferred to ``drain_chunk``. The staging set
-        is write-protected until the chunk drains."""
+        is write-protected until the chunk drains. ``span`` names the
+        fleet's dispatch steps and ``pool.feedback`` (see
+        ``repro.serve.spans``)."""
         K, C = staging.il.shape[:2]
         active = np.arange(K)[:, None] < np.asarray(counts)[None, :]
         base_idx = self._base_idx.copy()
@@ -431,44 +438,49 @@ class RobotStatePool:
         states, outs, work = self.fleet.dispatch_chunk(
             self.states, staging.il, staging.ir, staging.ac, staging.gy,
             staging.gps, self._mode.copy(), dt_imu, active=active,
-            stager=self._stager, base_idx=base_idx)
+            stager=self._stager, base_idx=base_idx, span=span)
         self.states = states
         self._base_idx += np.asarray(counts, self._base_idx.dtype)
         staging.protect()
-        needs_flush = False
-        if work.kalman_off:
-            # feedback: the boundary update must reach the next dispatch
-            self.states = self.fleet._host_kalman_fix(
-                self.states, outs, work.act)
-            needs_flush = True
-        if work.has_reg:
-            # the chunk-flush contract: Registration pose fixes sync
-            # their robots' slices and land before the next dispatch
-            self.states = self.fleet._registration_fix(
-                self.states, outs, work.mode_np, work.act)
-            needs_flush = True
+        needs_flush = work.kalman_off or work.has_reg
+        if needs_flush:
+            with span("pool.feedback"):
+                if work.kalman_off:
+                    # feedback: the boundary update must reach the next
+                    # dispatch
+                    self.states = self.fleet._host_kalman_fix(
+                        self.states, outs, work.act)
+                if work.has_reg:
+                    # the chunk-flush contract: Registration pose fixes
+                    # sync their robots' slices and land before the
+                    # next dispatch
+                    self.states = self.fleet._registration_fix(
+                        self.states, outs, work.mode_np, work.act)
         pending_slam = ((work.mode_np, work.act, work.base_idx)
                         if work.has_slam else None)
         return InFlightChunk(outs, tuple(manifest), staging,
                              pending_slam, needs_flush, retired=retired)
 
-    def drain_chunk(self, fl: InFlightChunk) -> Dict[Any, np.ndarray]:
+    def drain_chunk(self, fl: InFlightChunk,
+                    span: Callable[[str], ContextManager] = no_span
+                    ) -> Dict[Any, np.ndarray]:
         """The one pose sync: block until ``fl``'s chunk has executed,
         run its deferred SLAM replay, release its staging set, and
         route poses back through the manifest. Chunks must drain in
-        dispatch order (the engine's FIFO deque guarantees it)."""
-        t0 = time.perf_counter()
-        p = np.asarray(fl.outs.p)    # blocks until the chunk completes
-        fl.retired = None            # donated input state: now free
-        t_sync = time.perf_counter()
-        if fl.pending_slam is not None:
-            self.fleet._slam_replay(fl.outs, *fl.pending_slam)
-            fl.pending_slam = None
-        fl.staging.release()
-        # where this drain's wall time went (read by the engine's
-        # stage/dispatch/sync/host-stage decomposition trackers)
-        fl.meta["sync_s"] = t_sync - t0
-        fl.meta["host_s"] = time.perf_counter() - t_sync
+        dispatch order (the engine's FIFO deque guarantees it). The
+        sync also fetches the frames' work counters into ``fl.counts``;
+        ``span`` names ``pool.sync`` and ``pool.host_stage``."""
+        with span("pool.sync"):
+            # blocks until the chunk completes
+            p, counts = jax.device_get((fl.outs.p, fl.outs.counts))
+            fl.retired = None        # donated input state: now free
+        with span("pool.host_stage"):
+            if fl.pending_slam is not None:
+                self.fleet._slam_replay(fl.outs, *fl.pending_slam)
+                fl.pending_slam = None
+            fl.staging.release()
+        fl.counts = {rid: counts[:n, s].sum(axis=0)
+                     for rid, s, n in fl.manifest}
         return {rid: p[:n, s].copy() for rid, s, n in fl.manifest}
 
     def write_frames(self, staging: _StagingSet, slot: int,

@@ -12,7 +12,10 @@ Copied from ``src/repro/core/step.py`` @ pre-registry HEAD — do not
 "fix" or modernize it; its value is being exactly the old behavior.
 The ``flags`` argument is the new ``PlanFlags`` (its legacy
 ``kalman``/``marg``/``marg_pallas``/``slam`` views read the same
-decisions the old NamedTuple fields carried).
+decisions the old NamedTuple fields carried). The one later addition
+is ``FrameOutputs.counts``, the per-frame work counters, computed here
+from the monolith's own intermediates so the compiled step's counters
+are checked against an independent count.
 """
 import jax
 import jax.numpy as jnp
@@ -103,10 +106,15 @@ def localize_step_monolith(state, img_l, img_r, accel, gyro, gps, mode,
         prev_img=fe_carry.prev_img, prev_yx=fe_carry.prev_yx,
         prev_valid=fe_carry.prev_valid,
         frame_idx=state.frame_idx + 1, ba=ba_state)
+    counts = jnp.stack([
+        jnp.sum(tracks_valid[:, -1]).astype(jnp.int32),
+        jnp.where(do_consume, count, 0).astype(jnp.int32),
+        (do_consume & flags.kalman).astype(jnp.int32),
+        upd_skipped.astype(jnp.int32)])
     outs = FrameOutputs(fr=fr, p=filt.p, q=filt.q, hist=hist,
                         ba_cost=ba_state.last_cost, ba_ran=ba_ran,
                         upd_uv=upd_uv, upd_valid=upd_valid,
-                        upd_skipped=upd_skipped)
+                        upd_skipped=upd_skipped, counts=counts)
     return new_state, outs
 
 

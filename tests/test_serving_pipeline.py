@@ -12,7 +12,9 @@ The load-bearing claims, each pinned here:
   * the gather serves high-``priority`` robots first when
     ``gather_budget`` cannot drain everything;
   * latency accounting stamps poses at the actual drain point and
-    splits queue wait (submit->dispatch) from pipeline residence.
+    splits queue wait (arrival->dispatch) from pipeline residence;
+  * the host spans run on the engine's clock and nest inside the
+    dispatch, and the work counters sum only real frames.
 """
 import numpy as np
 import pytest
@@ -295,14 +297,127 @@ def test_latency_split_and_report(pool_pair, synthetic_sequence):
 
     rep = eng.latency_report()
     assert rep["inflight"] == 2 and rep["peak_inflight"] >= 1
-    assert set(rep["decomposition"]) == {"stage", "dispatch", "sync",
-                                         "host_stage"}
+    assert set(rep["decomposition"]) == {
+        "stage", "dispatch", "build", "h2d", "enqueue", "feedback",
+        "sync", "host_stage", "queue_wait", "in_flight"}
     assert rep["decomposition"]["sync"]["count"] == 1
     r = rep["per_robot"]["t0"]
     assert r["frames"] == 1
     assert r["p50_s"] >= r["queue_wait"]["p50_s"]
     assert r["in_pipeline"]["p50_s"] >= 0.0
     eng.submit_leave("t0")
+    eng.run_chunk()
+
+
+def _ticking_clock(start=0.0):
+    tick = [start]
+
+    def clock():
+        tick[0] += 1.0
+        return tick[0]
+    return clock
+
+
+def test_host_spans_split_dispatch(pool_pair, synthetic_sequence):
+    """Every host span runs on the engine's clock: the dispatch's parts
+    (build, h2d, enqueue, and feedback for a Registration chunk) sum to
+    at most the dispatch, and stage, dispatch, sync and host stage keep
+    one sample per chunk."""
+    seq = synthetic_sequence
+    dt = seq.dt / seq.imu_per_frame
+    _drain_pools(pool_pair)
+    eng = ServingEngine(pool_pair[1], chunk=2, dt_imu=dt,
+                        overflow="reject", inflight=2,
+                        clock=_ticking_clock())
+    eng.submit_join("v0", "vio")
+    eng.submit_join("g0", "registration")
+    for i in range(3):
+        eng.submit_frame("v0", *_frame(seq, i))
+        if i == 1:
+            eng.submit_frame("g0", *_frame(seq, i))
+        eng.run_chunk()
+    eng.flush()
+    d = {k: tr.samples for k, tr in eng.decomp.items()}
+    assert eng.dispatches == 3
+    for k in ("stage", "dispatch", "build", "h2d", "enqueue", "sync",
+              "host_stage"):
+        assert len(d[k]) == 3, k
+    # only the chunk holding the Registration frame paid feedback
+    assert len(d["feedback"]) == 1 == eng.flushes
+    feedback = [0.0, d["feedback"][0], 0.0]
+    for i in range(3):
+        parts = d["build"][i] + d["h2d"][i] + d["enqueue"][i] + feedback[i]
+        assert 0.0 < parts <= d["dispatch"][i]
+    assert len(d["queue_wait"]) == len(d["in_flight"]) == 4   # frames
+    for rid in ("v0", "g0"):
+        eng.submit_leave(rid)
+    eng.run_chunk()
+
+
+def test_arrival_stamp_drives_waits(pool_pair, synthetic_sequence):
+    """``submit_frame(..., t=)`` stamps the frame's arrival: its queue
+    wait and latency count from ``t``, and their difference is the
+    frame's in-flight time (dispatch-to-sync)."""
+    seq = synthetic_sequence
+    dt = seq.dt / seq.imu_per_frame
+    _drain_pools(pool_pair)
+    eng = ServingEngine(pool_pair[1], chunk=2, dt_imu=dt,
+                        overflow="reject", inflight=2,
+                        clock=_ticking_clock(100.0))
+    eng.submit_join("a0")
+    eng.submit_frame("a0", *_frame(seq, 0), t=5.0)    # arrived long ago
+    eng.submit_frame("a0", *_frame(seq, 1))           # arrives now
+    eng.run_chunk()
+    eng.flush()
+    (q_old, q_now), (l_old, l_now) = (eng.queue_waits["a0"],
+                                      eng.latencies["a0"])
+    assert q_old > 95.0 > q_now >= 0.0
+    assert q_old - q_now == l_old - l_now > 95.0
+    in_flight = eng.decomp["in_flight"].samples
+    assert in_flight == [l_old - q_old] * 2 and in_flight[0] > 0.0
+    assert eng.decomp["queue_wait"].samples == [q_old, q_now]
+    eng.submit_leave("a0")
+    eng.run_chunk()
+
+
+def test_counters_cover_only_real_frames(pool_pair, synthetic_sequence,
+                                         monkeypatch):
+    """The work counters sum each robot's real frames of its manifest
+    slot: values planted in padding frames and free slots never reach
+    them."""
+    import jax.numpy as jnp
+    from repro.core.step import COUNTS
+    seq = synthetic_sequence
+    dt = seq.dt / seq.imu_per_frame
+    _drain_pools(pool_pair)
+    pool = pool_pair[1]
+    fleet = pool.fleet
+    real, raw = fleet._fused_fleet_chunk, []
+
+    def chunk(states, inputs, flags, dt_imu):
+        act = np.asarray(inputs.active)     # the inputs are donated
+        states, outs = real(states, inputs, flags, dt_imu)
+        raw.append((act, np.asarray(outs.counts)))
+        junk = jnp.where(act[..., None], 0, 1000)
+        return states, outs._replace(counts=outs.counts + junk)
+    monkeypatch.setattr(fleet, "_fused_fleet_chunk", chunk)
+    eng = ServingEngine(pool, chunk=2, dt_imu=dt, overflow="reject",
+                        inflight=2)
+    eng.submit_join("c0")
+    for i in range(5):                      # chunks of 1 and 2 frames
+        eng.submit_frame("c0", *_frame(seq, i))
+        if i % 2 == 0:
+            eng.run_chunk()
+    eng.flush()
+    want = sum(c[act].sum(axis=0) for act, c in raw)
+    got = eng.counters()
+    assert [got[k] for k in COUNTS] == [int(v) for v in want]
+    assert got["frames"] == 5 and got["flushes"] == 0
+    assert got["tracks"] > 0 and max(got[k] for k in COUNTS) < 1000
+    assert got["per_robot"]["c0"] == {k: v for k, v in got.items()
+                                      if k not in ("flushes", "per_robot")}
+    assert eng.latency_report()["counters"] == got
+    eng.submit_leave("c0")
     eng.run_chunk()
 
 

@@ -8,6 +8,8 @@ results or speed. The topology is described inside a fixture — never
 while a module is imported — and every compile happens in the test's
 own process.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -105,7 +107,9 @@ def test_blocked_matmul_compiles(one_chip):
 def test_served_chunk_program_compiles(one_chip, monkeypatch):
     """The serving pool's one fleet chunk program at EDX-CAR shapes, with
     the kernel plan a TPU resolves (no fitted models): cov_update and
-    marg_schur on Pallas, frontend_fused on XLA (its tpu_blocker)."""
+    marg_schur on Pallas, frontend_fused on XLA (its tpu_blocker). The
+    Pallas calls carry their kernels' names and the op metadata every
+    primitive's named scope."""
     from repro.data import frames
     from repro.kernels import cov_update, frontend_fused, marg_schur, registry
     from repro.serve import RobotStatePool
@@ -137,4 +141,17 @@ def test_served_chunk_program_compiles(one_chip, monkeypatch):
         lambda x: _spec(one_chip, np.shape(x), x.dtype),
         (pool.states, inputs, flags, jnp.float32(1.0 / be.imu_rate_hz)))
     compiled = fleet._fused_fleet_chunk.lower(*args).compile()
-    assert compiled.as_text().count("tpu_custom_call") == 2
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    # each Pallas call is named after its kernel in the compiled program
+    # (and so in a device trace)
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    head = re.compile(r"\s*(?:ROOT )?%([a-z_]+)\.\d+ = ")
+    names = sorted(head.match(line).group(1) for line in calls)
+    assert names == ["cov_update", "marg_schur_normal"]
+    # every primitive's named scope survives into the op metadata
+    scopes = set()
+    for path in re.findall(r'op_name="([^"]*)"', text):
+        scopes.update(re.findall(r"[A-Za-z_]\w*", path))
+    assert {"frontend", "track_ring", "imu_propagate", "msckf_update",
+            "gps_fusion"} <= scopes
